@@ -1,22 +1,2 @@
 """repro — production-scale JAX/Pallas reproduction of adaptive sampled
-softmax with an inverted multi-index (see DESIGN.md for the architecture).
-
-Importing the package installs a tiny forward-compat shim for jax APIs the
-distribution layer is written against (DESIGN §4): `jax.set_mesh(mesh)` —
-present in jax ≥ 0.5 — is mapped onto the classic `with mesh:` context on
-older jax. The shim never overrides a real implementation.
-"""
-from __future__ import annotations
-
-import jax
-
-if not hasattr(jax, "set_mesh"):
-    def _set_mesh(mesh):
-        """Compat: `with jax.set_mesh(mesh):` ≡ `with mesh:` on jax < 0.5.
-
-        jax.sharding.Mesh is itself a context manager that installs the
-        ambient mesh, which is all the newer API does for concrete meshes.
-        """
-        return mesh
-
-    jax.set_mesh = _set_mesh
+softmax with an inverted multi-index (see DESIGN.md for the architecture)."""

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -64,6 +63,7 @@ def flash_decode_seq_sharded(q, k_cache, v_cache, pos, mesh, *,
         out = acc / jnp.moveaxis(l, 3, 1)[..., None]      # [b,1,kv,g,hd]
         return out.reshape(b, 1, h, hd).astype(q.dtype)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(None, axis), P(None, axis), P()),
-                     out_specs=P(), check_rep=False)(q, k_cache, v_cache, pos)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(None, axis), P(None, axis), P()),
+                         out_specs=P(), check_vma=False)(q, k_cache, v_cache,
+                                                         pos)

@@ -240,7 +240,6 @@ def loss_midx_vp(cfg, table_local: jax.Array, local_idx: MultiIndex,
     shard = jax.lax.axis_index(axis)
     h32 = hidden.astype(jnp.float32)
     b, s, d = h32.shape
-    interpret = interpret or kd.interpret_default()
     use_fused = kd.fused_head_active(cfg.head, fused=fused,
                                     interpret=interpret)
     fmt = resolve_table_dtype(getattr(cfg.head, "table_dtype", "bf16"))

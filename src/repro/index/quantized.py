@@ -140,10 +140,11 @@ def dequant_rows(master: jax.Array, data: jax.Array, scale: jax.Array,
 
 def _dequant_rows_fwd(master, data, scale, ids):
     out = data[ids].astype(jnp.float32) * scale[ids]
-    # residuals must be real arrays (shard_map/pjit moves them across the
-    # fwd/bwd boundary): a [0, D] slice keeps master's shape[1:]/dtype, the
-    # tiny [V, 1] scale supplies the row count.
-    dead = jax.lax.slice_in_dim(master, 0, 0, axis=0)
+    # residuals must be real, non-empty arrays (shard_map/pjit moves them
+    # across the fwd/bwd boundary, and a zero-size one loses its sharding):
+    # a [1, D] slice keeps master's shape[1:]/dtype, the tiny [V, 1] scale
+    # supplies the row count.
+    dead = jax.lax.slice_in_dim(master, 0, 1, axis=0)
     return out, (dead, scale, ids)
 
 

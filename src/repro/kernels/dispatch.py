@@ -4,22 +4,21 @@ One place answers "kernel or jnp oracle?" for every fused hot path, so the
 decision is uniform across heads/steps/benchmarks:
 
   - TPU backend        -> compiled Pallas kernels (the production path).
-  - anything else      -> jnp oracle fallback (what the CPU dry-run and the
-                          tier-1 suite compile), unless interpret mode is
-                          forced, in which case the *kernel dataflow* runs
-                          under the Pallas interpreter (parity tests, and
-                          compile-only dry-runs of the fused graph).
+  - anything else      -> jnp oracle (what the CPU dry-run and the tier-1
+                          suite compile), unless the caller passes
+                          `interpret=True`, in which case the *kernel
+                          dataflow* runs under the Pallas interpreter
+                          (parity tests, and compile-only dry-runs of the
+                          fused graph).
 
-Env overrides (read at trace time, for experiments — not config):
-  REPRO_FUSED_HEAD=0|1      force the fused head off/on everywhere.
-  REPRO_PALLAS_INTERPRET=1  run kernels interpreted on non-TPU backends.
+Only the backend and the caller's arguments decide; nothing in the
+environment can divert the TPU path to the interpreter or the oracle.
 
 `core/` stays kernel-free: the samplers take a `tables_fn` hook, and this
 module is where models/launch obtain one.
 """
 from __future__ import annotations
 
-import os
 from typing import Callable, Optional
 
 import jax
@@ -32,34 +31,20 @@ def pallas_supported() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _env_flag(name: str) -> Optional[bool]:
-    v = os.environ.get(name, "").strip().lower()
-    if not v:
-        return None
-    return v not in ("0", "false", "no", "off")
-
-
-def interpret_default() -> bool:
-    return bool(_env_flag("REPRO_PALLAS_INTERPRET"))
-
-
 def fused_head_active(head_cfg, *, fused: Optional[bool] = None,
                       interpret: bool = False) -> bool:
     """Should `loss_midx` take the fused kernel path?
 
-    Explicit `fused` wins; else REPRO_FUSED_HEAD; else
-    `head_cfg.use_fused_head` gated on a backend that can run the kernels
-    (TPU, or interpret mode). The fused kernels always mask collisions, so
-    `mask_collisions=False` configs stay on the jnp path.
+    Explicit `fused` wins; else `head_cfg.use_fused_head` gated on a
+    backend that can run the kernels (TPU, or interpret mode). The fused
+    kernels always mask collisions, so `mask_collisions=False` configs stay
+    on the jnp path.
     """
     if not head_cfg.mask_collisions:
         return False
-    if fused is None:
-        fused = _env_flag("REPRO_FUSED_HEAD")
     if fused is not None:
         return fused
-    return head_cfg.use_fused_head and (pallas_supported() or interpret
-                                        or interpret_default())
+    return head_cfg.use_fused_head and (pallas_supported() or interpret)
 
 
 def midx_tables_fn(*, use_kernel: Optional[bool] = None,
@@ -73,7 +58,6 @@ def midx_tables_fn(*, use_kernel: Optional[bool] = None,
     (differentiable; see kernels/midx_probs/ops.py).
     """
     from repro.kernels.midx_probs.ops import proposal_tables
-    interpret = interpret or interpret_default()
     if use_kernel is None:
         use_kernel = pallas_supported() or interpret
 
@@ -99,7 +83,6 @@ def midx_tables_fn_q(qcb1, sc1, qcb2, sc2, *,
     post-dot dequant as the kernel and agrees bit-for-bit.
     """
     from repro.kernels.midx_probs.ops import proposal_tables_q
-    interpret = interpret or interpret_default()
     if use_kernel is None:
         use_kernel = pallas_supported() or interpret
 
@@ -121,7 +104,6 @@ def rff_sample_fn(*, use_kernel: Optional[bool] = None,
     draws are bit-identical either way (kernels/rff_sample/ops.py).
     """
     from repro.kernels.rff_sample.ops import rff_gumbel_sample
-    interpret = interpret or interpret_default()
     if use_kernel is None:
         use_kernel = pallas_supported() or interpret
 

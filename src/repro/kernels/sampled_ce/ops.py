@@ -52,7 +52,7 @@ sampled_ce_op.defvjp(_fwd, _bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def sampled_ce_pt_op(hidden, table, log_q, neg_ids, pos_ids,
                      interpret: bool = False, block_t: int = 128,
-                     chunk: int = 8):
+                     chunk: int = 128):
     """Per-token fused CE. hidden [T,D]; table [V,D] native dtype;
     log_q/neg_ids [T,M]; pos_ids [T] -> loss [T] fp32."""
     loss, _ = sampled_ce_pt(hidden, table, log_q, neg_ids, pos_ids,
@@ -123,7 +123,7 @@ sampled_ce_partial_op.defvjp(_partial_fwd, _partial_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def sampled_ce_pt_partial_op(hidden, table, log_q, neg_ids, pos_ids,
                              num_neg: int, interpret: bool = False,
-                             block_t: int = 128, chunk: int = 8):
+                             block_t: int = 128, chunk: int = 128):
     """Per-token partial lse. table is this shard's row slice; neg_ids are
     LOCAL rows (non-owned clipped + log_q=-NEG_INF); pos_ids local-or--1.
     -> partial lse [T] fp32."""
@@ -165,17 +165,18 @@ sampled_ce_pt_partial_op.defvjp(_pt_partial_fwd, _pt_partial_bwd)
 # ---------------------------------------------------------------------------
 
 def _dead(x):
-    """Residual standing in for a dead primal: a zero-size slice that keeps
-    only the dtype (the bwd rules read `.dtype`, never the values). A real
-    (empty) array rather than an aval so the residual stays a valid JAX
-    type when custom_vjp runs under shard_map / pjit."""
-    return jax.lax.slice_in_dim(x, 0, 0, axis=0)
+    """Residual standing in for a dead primal: a one-row slice that keeps
+    only the dtype (the bwd rules read `.dtype`, never the values). A real,
+    non-empty array rather than an aval so the residual stays a valid JAX
+    type when custom_vjp runs under shard_map / pjit (a zero-size residual
+    loses its sharding there)."""
+    return jax.lax.slice_in_dim(x, 0, 1, axis=0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
 def sampled_ce_pt_q_op(hidden, table, qdata, qscale, log_q, neg_ids, pos_ids,
                        interpret: bool = False, block_t: int = 128,
-                       chunk: int = 8):
+                       chunk: int = 128):
     """Per-token fused CE over the quantized table. table [V,D] master
     (dead primal); qdata [V,D] int8/fp8; qscale [V,1] fp32 -> loss [T]."""
     del table  # dead in the forward: the kernel reads qdata + qscale
@@ -210,7 +211,7 @@ sampled_ce_pt_q_op.defvjp(_pt_q_fwd, _pt_q_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def sampled_ce_pt_q_partial_op(hidden, table, qdata, qscale, log_q, neg_ids,
                                pos_ids, num_neg: int, interpret: bool = False,
-                               block_t: int = 128, chunk: int = 8):
+                               block_t: int = 128, chunk: int = 128):
     """Quantized per-token partial lse (vocab-parallel shard): qdata/qscale
     are this shard's row slices; semantics as sampled_ce_pt_partial_op."""
     del table

@@ -50,8 +50,8 @@ def _kernel(h_ref, pe_ref, ne_ref, lq_ref, nid_ref, pid_ref, *rest,
         ne = ne * ns_ref[...]                            # per-row dequant
     logits = jax.lax.dot_general(h, ne, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [Tb,Mb]
-    corr = logits - (jnp.log(float(num_neg)) + lq_ref[...])[None, :]
-    hit = nid_ref[...][None, :] == pid_ref[...][:, None]          # [Tb, Mb]
+    corr = logits - (jnp.log(float(num_neg)) + lq_ref[...])       # [1, Mb]
+    hit = nid_ref[...] == pid_ref[...]             # [1, Mb] vs [Tb, 1]
     corr = jnp.where(hit, NEG_INF, corr)
     # validity guard: masked/padded entries contribute exactly 0 even when
     # the running max itself is NEG_INF (exp(corr - m) would be 1, not 0).
@@ -100,13 +100,15 @@ def _pad_dim(x: jax.Array, mult: int, axis: int = 0, fill=0):
 def _padded(hidden, pos_emb, neg_emb, log_q, neg_ids, pos_ids, block_t,
             block_m):
     """Pad every operand to the block grid: padded negatives are invalidated
-    via log_q, padded token rows are sliced off by the callers."""
+    via log_q, padded token rows are sliced off by the callers. The id and
+    log_q vectors become 2-D — [1, Mp] rows and a [Tp, 1] column — so every
+    block is tile-aligned for the TPU (a 1-D block is not)."""
     return (_pad_dim(hidden, block_t),
             _pad_dim(pos_emb, block_t),
             _pad_dim(neg_emb, block_m),
-            _pad_dim(log_q, block_m, fill=-NEG_INF),
-            _pad_dim(neg_ids, block_m, fill=-1),
-            _pad_dim(pos_ids, block_t, fill=-2))
+            _pad_dim(log_q, block_m, fill=-NEG_INF)[None, :],
+            _pad_dim(neg_ids, block_m, fill=-1)[None, :],
+            _pad_dim(pos_ids, block_t, fill=-2)[:, None])
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_m",
@@ -145,9 +147,9 @@ def sampled_ce(hidden: jax.Array, pos_emb: jax.Array, neg_emb: jax.Array,
         pl.BlockSpec((block_t, d), lambda it, im: (it, 0)),
         pl.BlockSpec((block_t, d), lambda it, im: (it, 0)),
         pl.BlockSpec((block_m, d), lambda it, im: (im, 0)),
-        pl.BlockSpec((block_m,), lambda it, im: (im,)),
-        pl.BlockSpec((block_m,), lambda it, im: (im,)),
-        pl.BlockSpec((block_t,), lambda it, im: (it,)),
+        pl.BlockSpec((1, block_m), lambda it, im: (0, im)),
+        pl.BlockSpec((1, block_m), lambda it, im: (0, im)),
+        pl.BlockSpec((block_t, 1), lambda it, im: (it, 0)),
     ]
     operands = [hidden, pos_emb, neg_emb, log_q, neg_ids, pos_ids]
     if quantized:
@@ -195,8 +197,8 @@ def _w_block(h, ne_ref, lq_ref, nid_ref, pid_ref, lse, *, num_neg: int,
         ne = ne * ns_ref[...]                            # per-row dequant
     logits = jax.lax.dot_general(h, ne, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    corr = logits - (jnp.log(float(num_neg)) + lq_ref[...])[None, :]
-    hit = nid_ref[...][None, :] == pid_ref[...][:, None]
+    corr = logits - (jnp.log(float(num_neg)) + lq_ref[...])
+    hit = nid_ref[...] == pid_ref[...]
     corr = jnp.where(hit, NEG_INF, corr)
     w = jnp.where(corr > NEG_INF_THRESHOLD, jnp.exp(corr - lse), 0.0)
     return w, ne
@@ -309,9 +311,9 @@ def sampled_ce_bwd(g: jax.Array, hidden: jax.Array, pos_emb: jax.Array,
         pl.BlockSpec((block_t, d), lambda it, im: (it, 0)),
         pl.BlockSpec((block_t, d), lambda it, im: (it, 0)),
         pl.BlockSpec((block_m, d), lambda it, im: (im, 0)),
-        pl.BlockSpec((block_m,), lambda it, im: (im,)),
-        pl.BlockSpec((block_m,), lambda it, im: (im,)),
-        pl.BlockSpec((block_t,), lambda it, im: (it,)),
+        pl.BlockSpec((1, block_m), lambda it, im: (0, im)),
+        pl.BlockSpec((1, block_m), lambda it, im: (0, im)),
+        pl.BlockSpec((block_t, 1), lambda it, im: (it, 0)),
         pl.BlockSpec((block_t, 1), lambda it, im: (it, 0)),
     ]
     dh_operands = [g2, hidden, pos_emb, neg_emb, log_q, neg_ids, pos_ids,
@@ -340,9 +342,9 @@ def sampled_ce_bwd(g: jax.Array, hidden: jax.Array, pos_emb: jax.Array,
         pl.BlockSpec((block_t, 1), lambda im, it: (it, 0)),
         pl.BlockSpec((block_t, d), lambda im, it: (it, 0)),
         pl.BlockSpec((block_m, d), lambda im, it: (im, 0)),
-        pl.BlockSpec((block_m,), lambda im, it: (im,)),
-        pl.BlockSpec((block_m,), lambda im, it: (im,)),
-        pl.BlockSpec((block_t,), lambda im, it: (it,)),
+        pl.BlockSpec((1, block_m), lambda im, it: (0, im)),
+        pl.BlockSpec((1, block_m), lambda im, it: (0, im)),
+        pl.BlockSpec((block_t, 1), lambda im, it: (it, 0)),
         pl.BlockSpec((block_t, 1), lambda im, it: (it, 0)),
     ]
     dne_operands = [g2, hidden, neg_emb, log_q, neg_ids, pos_ids, lse2]

@@ -1,7 +1,21 @@
-"""Production mesh builders (functions, never module-level state)."""
+"""Production mesh builders (functions, never module-level state).
+
+Every mesh here has `Auto` axes: the train, refresh and decode paths place
+their data with `shard_map` specs and leave the rest to the partitioner,
+which is what `Auto` means. (`jax.make_mesh` would otherwise build
+`Explicit` axes, under which those paths refuse to trace.)
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis `Auto` — the one mesh constructor of
+    the program and its tests."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, vocab_parallel: int = 1):
@@ -22,20 +36,20 @@ def make_production_mesh(*, multi_pod: bool = False, vocab_parallel: int = 1):
                  else (16, model, vocab_parallel))
         axes = (("pod", "data", "model", "vocab") if multi_pod
                 else ("data", "model", "vocab"))
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — for tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_vocab_mesh(data: int = 1, vocab: int = 1):
     """(data, vocab) mesh for the vocab-parallel head — tests + small runs."""
-    return jax.make_mesh((data, vocab), ("data", "vocab"))
+    return make_mesh((data, vocab), ("data", "vocab"))
 
 
 def mesh_dp_tp(mesh) -> tuple[int, int]:

@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import pad_to
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import Engine, Request, Router
 
 
@@ -184,6 +185,7 @@ def main():
                     help="run a compile-absorbing warmup first so reported "
                          "latency percentiles are steady-state (0 disables)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

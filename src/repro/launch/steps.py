@@ -244,7 +244,6 @@ def make_sharded_train_step(cfg: ModelConfig, optimizer: Optimizer, mesh, *,
     a [dp, ...]-stacked tree sharded over the data axes (each shard carries
     its own quantization residual; it is never replicated).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import collectives
 
@@ -295,11 +294,11 @@ def make_sharded_train_step(cfg: ModelConfig, optimizer: Optimizer, mesh, *,
             **metrics, "grad_norm": gnorm,
             "skipped": 1.0 - ok.astype(jnp.float32)}, ef
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(ax), P(), P(ax)),
         out_specs=(P(), P(), P(), P(ax)),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_vocab_parallel_train_step(cfg: ModelConfig, optimizer: Optimizer,
@@ -328,7 +327,6 @@ def make_vocab_parallel_train_step(cfg: ModelConfig, optimizer: Optimizer,
     by the same factor. The step key folds over the DATA shard index only:
     vocab shards must draw identical negatives.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import vocab_parallel as vp_mod
     from repro.dist.sharding import vocab_index_specs, vocab_param_specs
@@ -407,11 +405,11 @@ def make_vocab_parallel_train_step(cfg: ModelConfig, optimizer: Optimizer,
             **metrics, "grad_norm": gnorm,
             "skipped": 1.0 - ok.astype(jnp.float32)}
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, ospecs, idx_specs, P(dax), P()),
         out_specs=(pspecs, ospecs, P()),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_vocab_index_init(cfg: ModelConfig, mesh, *,
@@ -420,7 +418,6 @@ def make_vocab_index_init(cfg: ModelConfig, mesh, *,
     (index.sharded.build_vocab_sharded): codebook statistics psum, the CSR
     state never leaves its shard. `params` arrive sharded by
     vocab_param_specs, so each shard quantizes only its own table rows."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import vocab_parallel as vp_mod
     from repro.dist.sharding import vocab_index_specs, vocab_param_specs
@@ -444,8 +441,8 @@ def make_vocab_index_init(cfg: ModelConfig, mesh, *,
             cfg.head.quantizer, n_vp, cb1, cb2, a1[None], a2[None],
             si[None], off[None], cnt[None], lcnt[None])
 
-    return shard_map(body, mesh=mesh, in_specs=(pspecs, P()),
-                     out_specs=idx_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(pspecs, P()),
+                         out_specs=idx_specs, check_vma=False)
 
 
 def make_vocab_refresh_step(cfg: ModelConfig, mesh, *,
@@ -454,7 +451,6 @@ def make_vocab_refresh_step(cfg: ModelConfig, mesh, *,
     """refresh(params, sharded_index, key) -> (sharded_index, metrics) for
     the vocab-parallel layout: psum'd drift probe + warm-started sharded
     refit, each shard rebuilding only its local CSR (no all-gather)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import vocab_parallel as vp_mod
     from repro.dist.sharding import vocab_index_specs, vocab_param_specs
@@ -482,8 +478,9 @@ def make_vocab_refresh_step(cfg: ModelConfig, mesh, *,
             a2[None], si[None], off[None], cnt[None], lcnt[None])
         return new, metrics
 
-    return shard_map(body, mesh=mesh, in_specs=(pspecs, idx_specs, P()),
-                     out_specs=(idx_specs, P()), check_rep=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(pspecs, idx_specs, P()),
+                         out_specs=(idx_specs, P()), check_vma=False)
 
 
 def make_prefill_step(cfg: ModelConfig, *, window: Optional[int] = None):
@@ -562,7 +559,6 @@ def make_refresh_step(cfg: ModelConfig, mesh=None, *,
     if mesh is None:
         return refresh_replicated
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist.sharding import refresh_rows_per_shard
     from repro.index.sharded import refresh_sharded
@@ -593,8 +589,9 @@ def make_refresh_step(cfg: ModelConfig, mesh=None, *,
                                threshold=cfg.head.refresh_drift_threshold,
                                n_valid=n_valid)
 
-    sharded_step = shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
-                             out_specs=(P(), P()), check_rep=False)
+    sharded_step = jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(), P(), P()),
+                                 out_specs=(P(), P()), check_vma=False)
     if resolve_table_dtype(cfg.head.table_dtype) == "bf16":
         return sharded_step
 

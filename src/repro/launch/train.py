@@ -29,6 +29,7 @@ from repro.configs import get_config
 from repro.data import ZipfLM, make_lm_stream
 from repro.index import IndexLifecycle
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import (make_debug_mesh, make_vocab_mesh, mesh_dp_tp,
                                mesh_vp)
 from repro.models import heads, init_params
@@ -376,7 +377,8 @@ def main():
                     help="fused Pallas MIDX head (DESIGN §3): auto = "
                          "cfg.head.use_fused_head gated on backend; on = "
                          "compiled kernels (TPU only); interpret = fused "
-                         "graph via the Pallas interpreter (any backend)")
+                         "graph via the Pallas interpreter and off = the "
+                         "jnp head (CPU test tools, refused on a TPU)")
     ap.add_argument("--table-dtype", default=None,
                     help="class-table storage on the head hot path "
                          "(DESIGN §12): bf16 = master precision (default), "
@@ -403,6 +405,7 @@ def main():
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed of the injector's (seed, step) fault streams")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -416,9 +419,13 @@ def main():
         mesh = make_debug_mesh(args.dp, 1) if args.dp > 0 else None
     fused = {"auto": None, "on": True, "interpret": True,
              "off": False}[args.fused_head]
-    if args.fused_head == "on" and jax.default_backend() != "tpu":
+    on_tpu = jax.default_backend() == "tpu"
+    if args.fused_head == "on" and not on_tpu:
         raise SystemExit("--fused-head on compiles Pallas kernels and needs "
                          "a TPU backend; use --fused-head interpret here")
+    if args.fused_head in ("interpret", "off") and on_tpu:
+        raise SystemExit(f"--fused-head {args.fused_head} is a CPU test "
+                         "tool; on a TPU the head runs the compiled kernels")
     injector = None
     if args.chaos:
         injector = _parse_chaos(args.chaos, args.chaos_seed)

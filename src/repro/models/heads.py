@@ -146,7 +146,6 @@ def loss_midx(cfg: ModelConfig, params: dict, index: MultiIndex,
     m = cfg.head.num_negatives
     h32 = hidden.astype(jnp.float32)
     b, s, d = h32.shape
-    interpret = interpret or kd.interpret_default()
     use_fused = kd.fused_head_active(cfg.head, fused=fused,
                                     interpret=interpret)
 
@@ -347,7 +346,6 @@ def midx_decode_head(cfg: ModelConfig, params: dict, index: MultiIndex,
     table = class_embeddings(cfg, params)
     h = hidden.astype(jnp.float32)
     k_draw, k_pick = jax.random.split(key)
-    interpret = interpret or kd.interpret_default()
     use_fused = kd.fused_head_active(cfg.head, fused=fused,
                                     interpret=interpret)
     if qs is not None:
@@ -379,7 +377,6 @@ def _spec_tables_fn(cfg: ModelConfig, qs: Optional[QuantHeadState],
     """The same stage-table hook selection as midx_decode_head, so the
     speculative draft draws from exactly the distribution the serving head
     samples (quantized codebooks included, DESIGN §12)."""
-    interpret = interpret or kd.interpret_default()
     use_fused = kd.fused_head_active(cfg.head, fused=fused,
                                     interpret=interpret)
     if qs is not None:

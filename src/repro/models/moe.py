@@ -68,7 +68,6 @@ def apply_moe_sharded(p, x, *, top_k: int, capacity_factor: float = 1.25,
 
     Returns (y [B, S, D], aux scalar). Requires set_moe_mesh(...) first.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _SHARD_MODE["mesh"]
@@ -92,8 +91,8 @@ def apply_moe_sharded(p, x, *, top_k: int, capacity_factor: float = 1.25,
         sp = p["shared"]
         in_specs += [P(None, tp), P(None, tp), P(tp, None), P(None, None)]
         args += [sp["gate"], sp["up"], sp["down"], sp["shared_gate"]]
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=(bspec, P()), check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=(bspec, P()), check_vma=False)(*args)
 
 
 def _local_moe(x, router, w_gate, w_up, w_down, shared_w, *, top_k: int,

@@ -62,7 +62,7 @@ def rff_fused_sample_factory(*, use_kernel=None, interpret: bool = False):
     """sample(state, key, z, m) routed through kernels/rff_sample.
 
     `use_kernel=None` defers to kernels.dispatch (TPU -> compiled kernel,
-    else the bit-identical jnp oracle; REPRO_PALLAS_INTERPRET forces the
+    else the bit-identical jnp oracle; `interpret=True` forces the
     interpreter). The draw distribution equals the unfused `rff` proposal;
     only the noise source differs (hash counters vs jax.random), so log_prob
     and refresh are shared with it.

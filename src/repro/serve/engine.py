@@ -749,16 +749,19 @@ class Engine:
 
     # ------------------------------------------------------------ verification
     def replay_single(self, req: Request) -> np.ndarray:
-        """Run one request alone (1 slot) with the same weights, index and
-        key stream — the reference the batched output must match exactly
+        """Run one request alone with the same weights, index and key
+        stream — the reference the batched output must match exactly
         (DESIGN §5; speculative and chunked engines replay through the same
-        wave structure, so per-slot streams line up). The solo engine is
-        cached across calls so repeated verification doesn't recompile its
-        prefill/decode programs; reusing its state is safe because a
-        recycled slot's reads are masked to the new request's own writes."""
+        wave structure, so per-slot streams line up). The solo engine keeps
+        this engine's slot count, the other slots idle: a TPU may round a
+        one-row matmul differently from an eight-row one, so only the same
+        shapes make "alone" comparable bit for bit. It is cached across
+        calls so repeated verification doesn't recompile its prefill/decode
+        programs; reusing its state is safe because a recycled slot's reads
+        are masked to the new request's own writes."""
         if getattr(self, "_solo", None) is None:
-            self._solo = Engine(self.cfg.with_serve(max_slots=1), self.params,
-                                index=self.index, head=self.head,
-                                window=self.window, attn_fn=self.attn_fn)
+            self._solo = Engine(self.cfg, self.params, index=self.index,
+                                head=self.head, window=self.window,
+                                attn_fn=self.attn_fn)
         res = self._solo.run([dataclasses.replace(req, arrival=0.0)])
         return res[req.rid].tokens

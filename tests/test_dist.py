@@ -66,7 +66,8 @@ def test_flash_decode_seq_sharded_multi_device():
         import jax, jax.numpy as jnp, numpy as np
         from repro.dist import flash_decode_seq_sharded
         from repro.models.attention import decode_attention
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 8), ("data", "model"))
         B, S, H, KV, hd = 2, 64, 4, 2, 16
         key = jax.random.PRNGKey(0)
         q = jax.random.normal(key, (B, 1, H, hd))
@@ -96,7 +97,8 @@ def test_train_step_shards_on_multi_device_mesh():
         from repro.models import init_params, heads
         from repro.optim import adamw
         cfg = get_config("granite-moe-1b-a400m").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         key = jax.random.PRNGKey(0)
         params = init_params(cfg, key)
         opt = adamw(1e-3)
@@ -124,7 +126,8 @@ def test_moe_sharded_matches_local_multi_device():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.models import moe as moe_mod
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         key = jax.random.PRNGKey(0)
         B, S, D, E, F, K = 8, 16, 32, 4, 64, 2
         p = moe_mod.moe_init(key, D, F, E, shared_d_ff=48)
@@ -160,7 +163,8 @@ def test_elastic_restore_across_meshes(tmp_path):
         key = jax.random.PRNGKey(0)
         params = init_params(cfg, key)
         mgr = CheckpointManager({str(tmp_path)!r})
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         specs_a = param_specs(cfg, params, tp=2)
         with jax.set_mesh(mesh_a):
             p_a = jax.tree_util.tree_map(
@@ -168,7 +172,7 @@ def test_elastic_restore_across_meshes(tmp_path):
                 params, specs_a)
         mgr.save(1, p_a, metadata={{"mesh": [4, 2]}})
         for shape in ((2, 4), (8, 1)):
-            mesh_b = jax.make_mesh(shape, ("data", "model"))
+            mesh_b = make_mesh(shape, ("data", "model"))
             specs_b = param_specs(cfg, params, tp=shape[1])
             sh_b = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh_b, s), specs_b)
@@ -185,9 +189,9 @@ def test_compressed_psum_multi_device():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.dist.collectives import psum_bf16, psum_int8_ef
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         x = jnp.arange(8.0).reshape(8, 1) + 1.0
 
         def body(x):
@@ -196,8 +200,8 @@ def test_compressed_psum_multi_device():
             s_int8, ef = psum_int8_ef(g, {"w": jnp.zeros_like(x)}, "data")
             return s_bf16, s_int8["w"]
 
-        f = shard_map(body, mesh=mesh, in_specs=P("data"),
-                      out_specs=(P(None), P(None)))
+        f = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                          out_specs=(P(None), P(None)))
         a, b = f(x)
         np.testing.assert_allclose(np.asarray(a)[0], 36.0, rtol=1e-2)
         np.testing.assert_allclose(np.asarray(b)[0], 36.0, rtol=2e-2)

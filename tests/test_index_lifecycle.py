@@ -191,7 +191,6 @@ def test_sharded_refresh_multi_device():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.index import build, reassign, refresh_sharded, drift_metrics
 
         key = jax.random.PRNGKey(0)
@@ -201,15 +200,17 @@ def test_sharded_refresh_multi_device():
                                               (n, d))
         idx = build(jax.random.fold_in(key, 2), emb, kind="rq", k=k,
                     iters=4, keep_residuals=False)
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
 
         def make(policy):
             def body(index, key, table):
                 return refresh_sharded(index, key, table, axis="data",
                                        iters=4, policy=policy, threshold=0.2)
-            return jax.jit(shard_map(body, mesh=mesh,
-                                     in_specs=(P(), P(), P("data")),
-                                     out_specs=(P(), P()), check_rep=False))
+            return jax.jit(jax.shard_map(body, mesh=mesh,
+                                         in_specs=(P(), P(), P("data")),
+                                         out_specs=(P(), P()),
+                                         check_vma=False))
 
         # reassign path (drift below threshold on the unchanged table)
         out, m = make("drift")(idx, jax.random.fold_in(key, 3), emb)
@@ -252,7 +253,8 @@ def test_make_refresh_step_sharded_smoke():
     # with frozen params — only the reassigned fraction is exactly 0
     cfg = get_config("paper-lm").reduced().with_head(
         refresh_drift_threshold=0.5)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     key = jax.random.PRNGKey(0)
     params = init_params(cfg, key)
     index = heads.init_head_state(cfg, params, jax.random.fold_in(key, 1))

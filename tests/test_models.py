@@ -116,6 +116,26 @@ def test_mamba2_chunked_equals_recurrence(key):
                                np.asarray(y8), atol=1e-5)
 
 
+def test_mamba2_full_chunk_gradient_is_finite(key):
+    """At the published chunk (256) the decay above the causal diagonal
+    exceeds exp's fp32 range; the masked entries must not leak inf·0 = NaN
+    into the gradient."""
+    from repro.models import mamba2 as mm
+    d_model, d_state, head_dim, expand = 64, 16, 16, 2
+    p = mm.mamba2_init(key, d_model, d_state=d_state, head_dim=head_dim,
+                       expand=expand, conv_width=4)
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (1, 256, d_model))
+
+    def loss(p, x):
+        y = mm.apply_mamba2(p, x, d_state=d_state, head_dim=head_dim,
+                            expand=expand, chunk=256)
+        return jnp.mean(y.astype(jnp.float32) ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1))(p, x)
+    for g in jax.tree_util.tree_leaves(grads):
+        assert bool(jnp.all(jnp.isfinite(g)))
+
+
 def test_attention_chunked_equals_direct(key):
     """Flash (fwd + custom-vjp bwd) path == direct einsum path."""
     from repro.models.attention import _direct_attention, attention

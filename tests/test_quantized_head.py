@@ -316,7 +316,6 @@ def test_vocab_parallel_quantized_parity(proposal, fused):
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.configs.base import HeadConfig, ModelConfig
     from repro.dist import vocab_parallel as vp
     from repro.dist import sharding as shd
@@ -342,15 +341,16 @@ def test_vocab_parallel_quantized_parity(proposal, fused):
     n = 8
 
     table = class_embeddings(cfg, params).astype(jnp.float32)
-    mesh = jax.make_mesh((n,), ("vocab",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((n,), ("vocab",))
     sharded = vp.shard_index(index, n)
     idx_specs = shd.vocab_index_specs(sharded)
     tbl_spec = shd.head_table_spec(padded_vocab=table.shape[0], vp=n)
 
     def vp_loss(tbl, hh):
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(tbl_spec, idx_specs, P()),
-                           out_specs=P(), check_rep=False)
+                           out_specs=P(), check_vma=False)
         def body(t, si, z):
             return vp.loss_midx_vp(cfg, t, vp.local_index(si), z, labels,
                                    skey, axis="vocab", fused=fused,

@@ -42,6 +42,26 @@ def test_zipf_lm_structure():
     assert top > counts.sum() * 0.3
 
 
+@pytest.mark.parametrize("vocab,clusters", [(200, 8), (3, 16)])
+def test_zipf_lm_matches_choice_loop(vocab, clusters):
+    """The vectorised draws are numpy's `rng.choice(n, p=row)`, call by
+    call, on the same stream."""
+    gen = ZipfLM(vocab_size=vocab, num_clusters=clusters, seq_len=12, seed=3)
+    token_cluster, trans, within, marginal = gen._tables()
+    rng = np.random.default_rng(gen.seed + 1)
+    ref = np.empty((16, 12), np.int32)
+    cur = rng.integers(0, clusters, size=16)
+    for t in range(12):
+        noise = rng.random(16) < gen.within_cluster_noise
+        coherent = [rng.choice(vocab, p=within[k]) for k in cur]
+        tok = np.where(noise, rng.choice(vocab, p=marginal, size=16),
+                       coherent)
+        ref[:, t] = tok
+        cur = np.array([rng.choice(clusters, p=trans[token_cluster[i]])
+                        for i in tok])
+    np.testing.assert_array_equal(gen.sample(16), ref)
+
+
 # ------------------------------------------------------------------- optim
 def test_adamw_converges_quadratic():
     opt = adamw(0.1, weight_decay=0.0)

@@ -147,8 +147,8 @@ _SETUP = """
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.configs.base import HeadConfig, ModelConfig
+    from repro.launch.mesh import make_mesh
     from repro.core import midx as midx_mod
     from repro.dist import vocab_parallel as vp
     from repro.dist import sharding as shd
@@ -179,12 +179,13 @@ def test_sample_twostage_vp_bitwise_parity():
     """Draw ids are BITWISE equal to the replicated two-stage sampler."""
     _run(_SETUP + """
     cfg, params, index, h, labels, skey = make("per_token")
-    mesh = jax.make_mesh((n,), ("vocab",))
+    mesh = make_mesh((n,), ("vocab",))
     sharded = vp.shard_index(index, n)
     idx_specs = shd.vocab_index_specs(sharded)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(idx_specs, P(), P()),
-                       out_specs=P(), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(idx_specs, P(), P()), out_specs=P(),
+                       check_vma=False)
     def draw(si, k, z):
         d = vp.sample_twostage_vp(vp.local_index(si), k, z,
                                   cfg.head.num_negatives, axis="vocab")
@@ -201,11 +202,11 @@ def test_embed_lookup_matches_gather():
     _run(_SETUP + """
     cfg, params, index, h, labels, skey = make("per_token")
     table = class_embeddings(cfg, params).astype(jnp.float32)
-    mesh = jax.make_mesh((n,), ("vocab",))
+    mesh = make_mesh((n,), ("vocab",))
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("vocab", None), P()), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
     def emb(t, tok):
         return vp.embed_lookup(t, tok, axis="vocab")
 
@@ -226,15 +227,15 @@ def test_loss_and_grad_parity(proposal, fused):
     """ + """
     cfg, params, index, h, labels, skey = make(proposal)
     table = class_embeddings(cfg, params).astype(jnp.float32)
-    mesh = jax.make_mesh((n,), ("vocab",))
+    mesh = make_mesh((n,), ("vocab",))
     sharded = vp.shard_index(index, n)
     idx_specs = shd.vocab_index_specs(sharded)
     tbl_spec = shd.head_table_spec(padded_vocab=table.shape[0], vp=n)
 
     def vp_loss(tbl, hh):
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(tbl_spec, idx_specs, P()),
-                           out_specs=P(), check_rep=False)
+                           out_specs=P(), check_vma=False)
         def body(t, si, z):
             return vp.loss_midx_vp(cfg, t, vp.local_index(si), z, labels,
                                    skey, axis="vocab", fused=fused,
@@ -266,7 +267,7 @@ def test_train_step_matches_replicated():
     tokens = jax.random.randint(jax.random.fold_in(key, 5), (2, 8), 0,
                                 cfg.vocab_size)
     batch = {"tokens": tokens, "labels": labels}
-    mesh = jax.make_mesh((1, n), ("data", "vocab"))
+    mesh = make_mesh((1, n), ("data", "vocab"))
     opt = adamw(1e-3)
     opt_state = opt.init(params)
     sharded = vp.shard_index(index, n)
@@ -297,7 +298,7 @@ def test_native_index_init_and_refresh():
     _run(_SETUP + """
     from repro.launch import steps as steps_mod
     cfg, params, index, h, labels, skey = make("per_token")
-    mesh = jax.make_mesh((1, n), ("data", "vocab"))
+    mesh = make_mesh((1, n), ("data", "vocab"))
     vpad = cfg.padded_vocab
     rows = vpad // n
 
@@ -329,10 +330,10 @@ def test_native_index_init_and_refresh():
     # replicated loss over a replicated build of the same table
     idx_specs = shd.vocab_index_specs(sh2)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(shd.head_table_spec(padded_vocab=vpad, vp=n),
                                  idx_specs, P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
     def loss(t, si, z):
         return vp.loss_midx_vp(cfg, t, vp.local_index(si), z, labels, skey,
                                axis="vocab", fused=False)
@@ -406,7 +407,8 @@ def test_refresh_pad_and_mask_non_dividing_matches_replicated():
     params = init_params(cfg, key)
     index = heads.init_head_state(cfg, params, jax.random.fold_in(key, 1))
     rkey = jax.random.fold_in(key, 2)
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
 
     for policy in ("drift", "fixed"):
         i_ref, m_ref = jax.jit(steps_mod.make_refresh_step(
