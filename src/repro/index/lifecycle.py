@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.index.build import (MultiIndex, _build_impl, _reassign_impl,
                                reassign)
 from repro.index.quantization import assign_against
+from repro.utils.trace import span
 
 REFRESH_POLICIES = ("fixed", "drift")
 
@@ -148,7 +149,8 @@ class RefreshEvent:
     """One completed refresh, as reported to the step log / metrics sink."""
     step: int                 # step whose params the rebuild used
     swap_step: int            # step at which the new index went live
-    seconds: float            # host wall time attributable to the refresh
+    seconds: float            # host wall time attributable to the refresh:
+                              # dispatch, wait and the validation gate
     metrics: dict             # drift / did_full / distortion (python floats)
     rejected: bool = False    # validation gate kept the old state
     reasons: tuple = ()       # why (repro.resilience.validate strings)
@@ -208,29 +210,30 @@ class IndexLifecycle:
                   current: Any = None) -> tuple[MultiIndex, RefreshEvent]:
         step, _ready, index, metrics, t_disp = self._pending
         self._pending = None
-        t0 = time.perf_counter()
-        # the state is any proposal pytree (MultiIndex, TAPAS pool, RFF
-        # features, ...) — block on the whole tree, not a MIDX-only leaf
-        jax.block_until_ready(index)
-        # blocked time + dispatch time = host cost attributable to refresh;
-        # device time hidden under the lag window is free by construction
-        seconds = (time.perf_counter() - t0) + t_disp
-        # validation gate (DESIGN §11): a degenerate rebuild (empty CSR,
-        # zeroed codebooks, NaN leaves) must never become the live proposal
-        # — keep the old state, record the rejection, keep training
-        if self.validate and current is not None:
-            from repro.resilience.validate import validate_state
-            reasons = validate_state(index, like=current)
-            if reasons:
-                ev = RefreshEvent(step, swap_step, seconds,
-                                  {k: float(v) for k, v in metrics.items()},
-                                  rejected=True, reasons=tuple(reasons))
-                self.events.append(ev)
-                return current, ev
-        ev = RefreshEvent(step, swap_step, seconds,
-                          {k: float(v) for k, v in metrics.items()})
+        with span("index.refresh_complete", step=step) as sp:
+            t0 = time.perf_counter()
+            # the state is any proposal pytree (MultiIndex, TAPAS pool, RFF
+            # features, ...) — block on the whole tree, not a MIDX-only leaf
+            jax.block_until_ready(index)
+            metrics = {k: float(v) for k, v in metrics.items()}
+            # validation gate (DESIGN §11): a degenerate rebuild (empty CSR,
+            # zeroed codebooks, NaN leaves) must never become the live
+            # proposal — keep the old state, record the rejection, keep
+            # training
+            reasons = ()
+            if self.validate and current is not None:
+                from repro.resilience.validate import validate_state
+                with span("index.validate"):
+                    reasons = tuple(validate_state(index, like=current))
+            # blocked time + gate + dispatch time = host cost attributable
+            # to the refresh; device time hidden under the lag window is
+            # free by construction
+            seconds = (time.perf_counter() - t0) + t_disp
+            sp.set_metadata(rejected=int(bool(reasons)))
+        ev = RefreshEvent(step, swap_step, seconds, metrics,
+                          rejected=bool(reasons), reasons=reasons)
         self.events.append(ev)
-        return index, ev
+        return (current if reasons else index), ev
 
     def step(self, step: int, params: Any,
              index: MultiIndex) -> tuple[MultiIndex, Optional[RefreshEvent]]:
@@ -244,9 +247,10 @@ class IndexLifecycle:
             index, event = self._complete(step, index)
         if (step + 1) % self.every == 0 and self._pending is None:
             key = jax.random.fold_in(self.base_key, step)
-            t0 = time.perf_counter()
-            new_index, metrics = self.refresh_fn(params, index, key)
-            t_disp = time.perf_counter() - t0
+            with span("index.refresh_dispatch", step=step):
+                t0 = time.perf_counter()
+                new_index, metrics = self.refresh_fn(params, index, key)
+                t_disp = time.perf_counter() - t0
             self._pending = (step, step + self.lag, new_index, metrics, t_disp)
             if self.lag == 0:
                 index, event = self._complete(step, index)

@@ -100,6 +100,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

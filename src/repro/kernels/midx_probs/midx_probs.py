@@ -103,5 +103,6 @@ def midx_probs(z: jax.Array, cb1: jax.Array, cb2: jax.Array,
             pl.BlockSpec((block_t, 1), lambda i: (i, 0)),
         ],
         out_shape=out_shape,
+        name="midx_probs",
         interpret=interpret,
     )(*operands)

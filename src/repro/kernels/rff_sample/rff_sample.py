@@ -128,6 +128,7 @@ def rff_sample(phi_z: jax.Array, phi_c: jax.Array, meta: jax.Array,
             pl.BlockSpec((block_t, 1), lambda i, j, n: (i, 0)),
         ],
         out_shape=out_shape,
+        name="rff_sample",
         interpret=interpret,
     )(meta, phi_z, phi_c)
     return ids, score, m_run, l_run

@@ -346,6 +346,7 @@ def sampled_ce_pt(hidden: jax.Array, table: jax.Array, log_q: jax.Array,
             jax.ShapeDtypeStruct((tp, 1), jnp.float32),
         ],
         scratch_shapes=scratch,
+        name="sampled_ce_pt",
         interpret=interpret,
     )(*operands)
     return loss[:t, 0], lse[:t, 0]
@@ -502,6 +503,7 @@ def sampled_ce_pt_bwd(g: jax.Array, hidden: jax.Array, table: jax.Array,
         ],
         scratch_shapes=scratch,
         input_output_aliases={len(operands) - 1: 2},
+        name="sampled_ce_pt_bwd",
         interpret=interpret,
     )(*operands)
     return dh[:t, :d], dtab[:, 0, :d], dlq[:t, :m]

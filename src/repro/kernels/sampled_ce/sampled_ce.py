@@ -175,6 +175,7 @@ def sampled_ce(hidden: jax.Array, pos_emb: jax.Array, neg_emb: jax.Array,
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
         ],
+        name="sampled_ce",
         interpret=interpret,
     )(*operands)
     return loss[:t, 0], lse[:t, 0]
@@ -336,6 +337,7 @@ def sampled_ce_bwd(g: jax.Array, hidden: jax.Array, pos_emb: jax.Array,
             jax.ShapeDtypeStruct((tp, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
+        name="sampled_ce_bwd_dh",
         interpret=interpret,
     )(*dh_operands)
     dne_in_specs = [
@@ -369,6 +371,7 @@ def sampled_ce_bwd(g: jax.Array, hidden: jax.Array, pos_emb: jax.Array,
             pltpu.VMEM((block_m, d), jnp.float32),
             pltpu.VMEM((1, block_m), jnp.float32),
         ],
+        name="sampled_ce_bwd_dne",
         interpret=interpret,
     )(*dne_operands)
     return dh[:t], dpe[:t], dne[:m], dlq[0, :m]

@@ -85,6 +85,7 @@ def ssd_scan(x: jax.Array, bmat: jax.Array, cmat: jax.Array, adt: jax.Array,
                                lambda bi, hi, ci: (bi, hi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((bt, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
+        name="ssd_scan",
         interpret=interpret,
     )(xt, bmat, cmat, adt_t, dt_t)
     return out.transpose(0, 2, 1, 3)

@@ -39,12 +39,13 @@ into the cache attention of every self-attn layer.
 
 The main loop is factored into resumable pieces — `start_run` / `tick` /
 `finish_run` — so `serve.router.Router` can multiplex N engine replicas on
-one host thread; `run` composes them for the single-engine case.
+one host thread; `run` composes them for the single-engine case. Each tick
+writes `repro/engine.*` spans, with counts, into the profiler's trace
+(`utils.trace.span`, DESIGN §5).
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Optional
 
@@ -61,6 +62,7 @@ from repro.models.decode import chunk_prefill_step
 from repro.serve.kv_pool import PagePool, PrefixCache
 from repro.serve.scheduler import Rejection, Request, Scheduler, SlotState
 from repro.utils import metrics as metrics_mod
+from repro.utils.trace import span
 
 #: families whose paged attention cache makes speculative rollback free
 #: (stale draft K/V past the committed position is overwritten before it is
@@ -94,6 +96,8 @@ class EngineStats:
     spec_drafted: int = 0           # draft tokens proposed
     spec_accepted: int = 0          # draft tokens accepted by the verifier
     prefill_chunks: int = 0         # chunked-prefill waves run
+    prefill_tokens: int = 0         # prompt tokens prefilled
+    prefill_padded: int = 0         # token rows the prefill programs ran
     latencies_s: list = dataclasses.field(default_factory=list)
 
     def counters(self) -> dict:
@@ -119,6 +123,11 @@ class EngineStats:
             out["spec_waves"] = self.spec_waves
         if self.prefill_chunks:
             out["prefill_chunks"] = self.prefill_chunks
+        if self.prefill_padded:
+            # the share of prefill rows that carry a prompt token: groups and
+            # chunks are padded to max_slots rows
+            out["prefill_token_use"] = round(
+                self.prefill_tokens / self.prefill_padded, 4)
         out.update({k: round(v, 3) for k, v in metrics_mod.latency_summary(
             self.latencies_s, counters=self.counters()).items()})
         return out
@@ -242,14 +251,19 @@ class Engine:
         # speculative engines sample the *first* token from the exact target
         # distribution too (the verify head), not the MIDX approximation
         first_head = "full" if self.spec_k else head
-        self._first_token = jax.jit(
-            lambda params, index, hidden, keys:
-            _sample_tokens(cfg, params, index, hidden, keys, first_head,
-                           None if self.spec_k else proposal))
+
+        def first_token_fn(params, index, hidden, keys):
+            return _sample_tokens(cfg, params, index, hidden, keys,
+                                  first_head,
+                                  None if self.spec_k else proposal)
+
+        self._first_token = jax.jit(first_token_fn)
+
+        def prefill_fn(params, toks, **kw):
+            return prefill(cfg, params, toks, window=window, **kw)
+
         # compiles once per prompt-length bucket (groups are padded)
-        self._prefill = jax.jit(
-            lambda params, toks, **kw:
-            prefill(cfg, params, toks, window=window, **kw))
+        self._prefill = jax.jit(prefill_fn)
         # admission hot path, batched: one fused call builds every admitted
         # request's base key (fold_in(PRNGKey(seed), rid), bit-identical to
         # the scalar construction), one vmapped fold_in derives a group's
@@ -262,7 +276,11 @@ class Engine:
             return base_keys.at[slots].set(base), base
 
         self._bind_keys_jit = jax.jit(bind_keys_fn, donate_argnums=(3,))
-        self._write_prefill = jax.jit(functools.partial(write_prefill, cfg),
+
+        def write_prefill_fn(state, cache, slots, *, plen):
+            return write_prefill(cfg, state, cache, slots, plen=plen)
+
+        self._write_prefill = jax.jit(write_prefill_fn,
                                       static_argnames=("plen",),
                                       donate_argnums=(0,))
         spec_on = bool(self.spec_k)
@@ -280,11 +298,11 @@ class Engine:
 
         self._first_group = jax.jit(first_group_fn, donate_argnums=(5,))
         if self.chunk:
-            self._chunk_step = jax.jit(
-                lambda params, toks, start, length, state:
-                chunk_prefill_step(cfg, params, toks, start, length, state,
-                                   window=window),
-                donate_argnums=(4,))
+            def chunk_fn(params, toks, start, length, state):
+                return chunk_prefill_step(cfg, params, toks, start, length,
+                                          state, window=window)
+
+            self._chunk_step = jax.jit(chunk_fn, donate_argnums=(4,))
         if self.spec_k:
             spec_k = self.spec_k
 
@@ -449,46 +467,52 @@ class Engine:
             groups.setdefault(len(ss.request.tokens), []).append(i)
         for plen, idxs in groups.items():
             sss = [admitted[i] for i in idxs]
-            t0 = time.perf_counter()
             # pad the group to max_slots rows so each prompt-length bucket
             # compiles exactly once (batch composition never changes a row's
             # arithmetic, so padding cannot change any request's output)
             g, b = len(sss), self.cfg.serve.max_slots
+            self.stats.prefill_tokens += g * plen
+            self.stats.prefill_padded += b * plen
+            with span("engine.prefill_group", plen=plen, tokens=g * plen,
+                      padded=b * plen):
+                t0 = time.perf_counter()
 
-            def stack(rows):
-                rows = list(rows) + [rows[0]] * (b - g)
-                return jnp.asarray(np.stack(rows))
+                def stack(rows):
+                    rows = list(rows) + [rows[0]] * (b - g)
+                    return jnp.asarray(np.stack(rows))
 
-            toks = stack([ss.request.tokens for ss in sss])
-            kw = {}
-            if self.cfg.family == "vlm":
-                kw["image_emb"] = stack([ss.request.image_emb for ss in sss])
-            if self.cfg.family == "audio":
-                kw["frames"] = stack([ss.request.frames for ss in sss])
-            hidden, cache = self._prefill(self.params, toks, **kw)
-            # pad the slot list the same way: the padded cache rows duplicate
-            # row 0 bitwise, so writing slot[0] again is a no-op — and every
-            # write_prefill call keeps a fixed shape (no per-group-size
-            # recompiles of its eager scatters)
-            slots = np.asarray([ss.slot for ss in sss] +
-                               [sss[0].slot] * (b - g), np.int32)
-            self.state = self._write_prefill(self.state, cache, slots,
-                                             plen=plen)
-            # key folding + spec hdraft stash + first-token sampling, fused:
-            # one dispatch instead of four (host dispatch is the admission
-            # bottleneck on a CPU host)
-            gidx = np.asarray(idxs + [idxs[0]] * (b - g), np.int32)
-            first, self._hdraft = self._first_group(
-                self.params, self.index, base, jnp.asarray(gidx), hidden,
-                self._hdraft, jnp.asarray(slots), plen - 1)
-            first = np.asarray(first)
-            for ss, tok in zip(sss, first[:g]):
-                ss.out.append(int(tok))
-                ss.prefill_pos = plen
-            dt = time.perf_counter() - t0
-            for ss in sss:            # first-token latency: this group only
-                ss.latencies.append(dt)
-            self.stats.latencies_s.extend(dt for _ in sss)
+                toks = stack([ss.request.tokens for ss in sss])
+                kw = {}
+                if self.cfg.family == "vlm":
+                    kw["image_emb"] = stack([ss.request.image_emb
+                                             for ss in sss])
+                if self.cfg.family == "audio":
+                    kw["frames"] = stack([ss.request.frames for ss in sss])
+                hidden, cache = self._prefill(self.params, toks, **kw)
+                # pad the slot list the same way: the padded cache rows
+                # duplicate row 0 bitwise, so writing slot[0] again is a
+                # no-op — and every write_prefill call keeps a fixed shape
+                # (no per-group-size recompiles of its eager scatters)
+                slots = np.asarray([ss.slot for ss in sss] +
+                                   [sss[0].slot] * (b - g), np.int32)
+                self.state = self._write_prefill(self.state, cache, slots,
+                                                 plen=plen)
+                # key folding + spec hdraft stash + first-token sampling,
+                # fused: one dispatch instead of four (host dispatch is the
+                # admission bottleneck on a CPU host)
+                gidx = np.asarray(idxs + [idxs[0]] * (b - g), np.int32)
+                first, self._hdraft = self._first_group(
+                    self.params, self.index, base, jnp.asarray(gidx), hidden,
+                    self._hdraft, jnp.asarray(slots), plen - 1)
+                with span("engine.device_wait"):
+                    first = np.asarray(first)
+                for ss, tok in zip(sss, first[:g]):
+                    ss.out.append(int(tok))
+                    ss.prefill_pos = plen
+                dt = time.perf_counter() - t0
+                for ss in sss:        # first-token latency: this group only
+                    ss.latencies.append(dt)
+                self.stats.latencies_s.extend(dt for _ in sss)
         self.stats.generated += len(admitted)
 
     def _admit_chunked(self, admitted: list[SlotState]) -> None:
@@ -521,25 +545,31 @@ class Engine:
         starts[slot] = start
         lens = np.zeros((b,), np.int32)
         lens[slot] = len(seg)
+        self.stats.prefill_tokens += len(seg)
+        self.stats.prefill_padded += b * self.chunk
         t0 = time.perf_counter()
-        hidden, self.state = self._chunk_step(
-            self.params, jnp.asarray(toks), jnp.asarray(starts),
-            jnp.asarray(lens), self.state)
-        ss.prefill_pos = end
-        self.stats.prefill_chunks += 1
-        if end == plen:
-            self._prefill_fifo.pop(0)
-            if self.cache is not None:
-                self.cache.insert(req.tokens, self.pool.table[slot])
-            key = jax.random.fold_in(ss.key, plen - 1)
-            if self.spec_k:
-                self._hdraft = self._hdraft.at[slot].set(
-                    hidden[slot, len(seg) - 1].astype(self._hdraft.dtype))
-            first = np.asarray(self._first_token(
-                self.params, self.index, hidden[slot, len(seg) - 1][None],
-                key[None]))
-            ss.out.append(int(first[0]))
-            self.stats.generated += 1
+        with span("engine.prefill_chunk", tokens=len(seg),
+                  padded=b * self.chunk):
+            hidden, self.state = self._chunk_step(
+                self.params, jnp.asarray(toks), jnp.asarray(starts),
+                jnp.asarray(lens), self.state)
+            ss.prefill_pos = end
+            self.stats.prefill_chunks += 1
+            if end == plen:
+                self._prefill_fifo.pop(0)
+                if self.cache is not None:
+                    self.cache.insert(req.tokens, self.pool.table[slot])
+                key = jax.random.fold_in(ss.key, plen - 1)
+                if self.spec_k:
+                    self._hdraft = self._hdraft.at[slot].set(
+                        hidden[slot, len(seg) - 1].astype(self._hdraft.dtype))
+                first = self._first_token(
+                    self.params, self.index, hidden[slot, len(seg) - 1][None],
+                    key[None])
+                with span("engine.device_wait"):
+                    first = np.asarray(first)
+                ss.out.append(int(first[0]))
+                self.stats.generated += 1
         dt = time.perf_counter() - t0
         ss.prefill_s += dt
         if not ss.prefilling:
@@ -592,41 +622,44 @@ class Engine:
         `start_run`). Returns what happened: 'prefill' (batched prefill
         wave), 'work' (chunk and/or decode wave), 'idle' (waiting on an
         arrival), 'done' (nothing queued or active)."""
-        for req in self.sched.drop_expired(now):
-            self.stats.timeouts += 1
-            self._results[req.rid] = RequestResult(
-                req.rid, np.zeros(0, np.int32), [],
-                status="timeout", reason="expired before admission")
-        self._expire(now)
-        admitted = self.sched.admit(now)
-        if admitted:
-            if self.chunk:
-                self._admit_chunked(admitted)
-            else:
-                self._prefill_wave(admitted)
-                self._retire()    # max_new == 1 finishes at prefill
-                return "prefill"
-        worked = False
-        if self._prefill_fifo:
-            # one prefill chunk per wave, interleaved with the decode wave
-            # below — a long prompt never stalls in-flight decodes
-            self._chunk_wave()
-            self._retire()        # max_new == 1 finishes at the last chunk
-            worked = True
-        decoding = {slot: ss for slot, ss in self.sched.active.items()
-                    if not ss.prefilling}
-        if decoding:
-            # hot-swap window: between decode waves, never mid-wave
-            self._maybe_swap()
-            if self.spec_k:
-                self._spec_wave(decoding)
-            else:
-                self._decode_wave(decoding)
-            self._retire()
-            worked = True
-        if worked:
-            return "work"
-        return "done" if self.sched.done else "idle"
+        with span("engine.tick"):
+            with span("engine.admit") as admit:
+                for req in self.sched.drop_expired(now):
+                    self.stats.timeouts += 1
+                    self._results[req.rid] = RequestResult(
+                        req.rid, np.zeros(0, np.int32), [],
+                        status="timeout", reason="expired before admission")
+                self._expire(now)
+                admitted = self.sched.admit(now)
+                admit.set_metadata(admitted=len(admitted))
+            if admitted:
+                if self.chunk:
+                    self._admit_chunked(admitted)
+                else:
+                    self._prefill_wave(admitted)
+                    self._retire()    # max_new == 1 finishes at prefill
+                    return "prefill"
+            worked = False
+            if self._prefill_fifo:
+                # one prefill chunk per wave, interleaved with the decode wave
+                # below — a long prompt never stalls in-flight decodes
+                self._chunk_wave()
+                self._retire()        # max_new == 1 finishes at the last chunk
+                worked = True
+            decoding = {slot: ss for slot, ss in self.sched.active.items()
+                        if not ss.prefilling}
+            if decoding:
+                # hot-swap window: between decode waves, never mid-wave
+                self._maybe_swap()
+                if self.spec_k:
+                    self._spec_wave(decoding)
+                else:
+                    self._decode_wave(decoding)
+                self._retire()
+                worked = True
+            if worked:
+                return "work"
+            return "done" if self.sched.done else "idle"
 
     def finish_run(self) -> dict[int, RequestResult]:
         self.stats.wall_s += time.perf_counter() - self._t_start
@@ -661,51 +694,57 @@ class Engine:
 
     def _decode_wave(self, decoding: dict[int, SlotState]) -> None:
         """One slot-packed single-token decode step over `decoding` slots."""
-        tokens, pos, active = self._pack(decoding)
-        t0 = time.perf_counter()
-        nxt, self.state = self._step(
-            self.params, self.index, self.state, jnp.asarray(tokens),
-            jnp.asarray(pos), self._base_keys, jnp.asarray(active))
-        nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
-        self.stats.steps += 1
-        for slot, ss in decoding.items():
-            ss.out.append(int(nxt[slot]))
-            ss.pos += 1
-            ss.latencies.append(dt)
-            self.stats.latencies_s.append(dt)
-            self.stats.generated += 1
+        with span("engine.decode_wave", active=len(decoding),
+                  slots=self.cfg.serve.max_slots):
+            tokens, pos, active = self._pack(decoding)
+            t0 = time.perf_counter()
+            nxt, self.state = self._step(
+                self.params, self.index, self.state, jnp.asarray(tokens),
+                jnp.asarray(pos), self._base_keys, jnp.asarray(active))
+            with span("engine.device_wait"):
+                nxt = np.asarray(nxt)
+            dt = time.perf_counter() - t0
+            self.stats.steps += 1
+            for slot, ss in decoding.items():
+                ss.out.append(int(nxt[slot]))
+                ss.pos += 1
+                ss.latencies.append(dt)
+                self.stats.latencies_s.append(dt)
+                self.stats.generated += 1
 
     def _spec_wave(self, decoding: dict[int, SlotState]) -> None:
         """One speculative wave: k drafted backbone steps inside a jitted
         scan + one batched verify, committing 1..k tokens per slot. Wave
         latency is charged per committed token (amortized: the wave's dt
         divided by its committed count — the steady streaming rate)."""
-        tokens, pos, active = self._pack(decoding)
-        t0 = time.perf_counter()
-        toks, n_commit, n_acc, self._hdraft, self.state = self._spec_step(
-            self.params, self.index, self.state, jnp.asarray(tokens),
-            jnp.asarray(pos), self._hdraft, self._base_keys,
-            jnp.asarray(active))
-        toks = np.asarray(toks)
-        n_commit = np.asarray(n_commit)
-        n_acc = np.asarray(n_acc)
-        dt = time.perf_counter() - t0
-        self.stats.steps += self.spec_k
-        self.stats.spec_waves += 1
-        for slot, ss in decoding.items():
-            c = min(int(n_commit[slot]),
-                    ss.request.max_new - len(ss.out))
-            ss.out.extend(int(t) for t in toks[:c, slot])
-            ss.pos += c
-            ss.drafted += self.spec_k
-            ss.accepted += int(n_acc[slot])
-            self.stats.spec_drafted += self.spec_k
-            self.stats.spec_accepted += int(n_acc[slot])
-            per_tok = dt / max(c, 1)
-            ss.latencies.extend(per_tok for _ in range(c))
-            self.stats.latencies_s.extend(per_tok for _ in range(c))
-            self.stats.generated += c
+        with span("engine.spec_wave", active=len(decoding),
+                  slots=self.cfg.serve.max_slots, k=self.spec_k):
+            tokens, pos, active = self._pack(decoding)
+            t0 = time.perf_counter()
+            toks, n_commit, n_acc, self._hdraft, self.state = self._spec_step(
+                self.params, self.index, self.state, jnp.asarray(tokens),
+                jnp.asarray(pos), self._hdraft, self._base_keys,
+                jnp.asarray(active))
+            with span("engine.device_wait"):
+                toks = np.asarray(toks)
+                n_commit = np.asarray(n_commit)
+                n_acc = np.asarray(n_acc)
+            dt = time.perf_counter() - t0
+            self.stats.steps += self.spec_k
+            self.stats.spec_waves += 1
+            for slot, ss in decoding.items():
+                c = min(int(n_commit[slot]),
+                        ss.request.max_new - len(ss.out))
+                ss.out.extend(int(t) for t in toks[:c, slot])
+                ss.pos += c
+                ss.drafted += self.spec_k
+                ss.accepted += int(n_acc[slot])
+                self.stats.spec_drafted += self.spec_k
+                self.stats.spec_accepted += int(n_acc[slot])
+                per_tok = dt / max(c, 1)
+                ss.latencies.extend(per_tok for _ in range(c))
+                self.stats.latencies_s.extend(per_tok for _ in range(c))
+                self.stats.generated += c
 
     # ------------------------------------------------------------ retirement
     def _drop_prefilling(self, slot: int) -> None:
@@ -714,17 +753,19 @@ class Engine:
 
     def _retire(self) -> None:
         done = [s for s, ss in self.sched.active.items() if ss.done]
-        for slot in done:
-            ss = self.sched.finish(slot)
-            self._drop_prefilling(slot)
-            self.state = reset_slot(self.state, slot)
-            self._results[ss.request.rid] = RequestResult(
-                ss.request.rid, np.asarray(ss.out, np.int32), ss.latencies)
-        # one table push for the whole batch of retirements: pool.free reset
-        # every freed row to TRASH_PAGE host-side, so the single upload
-        # matches reset_slot's per-slot zeroing
-        if done and "page_table" in self.state:
-            self.state["page_table"] = jnp.asarray(self.pool.table)
+        with span("engine.retire", retired=len(done)):
+            for slot in done:
+                ss = self.sched.finish(slot)
+                self._drop_prefilling(slot)
+                self.state = reset_slot(self.state, slot)
+                self._results[ss.request.rid] = RequestResult(
+                    ss.request.rid, np.asarray(ss.out, np.int32),
+                    ss.latencies)
+            # one table push for the whole batch of retirements: pool.free
+            # reset every freed row to TRASH_PAGE host-side, so the single
+            # upload matches reset_slot's per-slot zeroing
+            if done and "page_table" in self.state:
+                self.state["page_table"] = jnp.asarray(self.pool.table)
 
     def _expire(self, now: float) -> None:
         """Retire active slots whose deadline passed: the tokens generated so

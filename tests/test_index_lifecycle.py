@@ -317,6 +317,28 @@ def test_lifecycle_lag_zero_is_synchronous(emb):
     assert not lc.in_flight
 
 
+def test_refresh_seconds_count_the_validation_gate(emb, monkeypatch):
+    """The gate is part of a refresh's host cost: a gate that takes 50 ms
+    shows in RefreshEvent.seconds."""
+    import time
+
+    from repro.resilience import validate
+
+    gate = validate.validate_state
+
+    def slow_gate(state, like=None):
+        time.sleep(0.05)
+        return gate(state, like=like)
+
+    monkeypatch.setattr(validate, "validate_state", slow_gate)
+    idx = build(jax.random.PRNGKey(1), emb, kind="rq", k=K, iters=2,
+                keep_residuals=False)
+    lc = IndexLifecycle(_toy_refresh(0.0), every=1, lag=0,
+                        base_key=jax.random.PRNGKey(0))
+    _, ev = lc.step(0, None, idx)
+    assert not ev.rejected and ev.seconds >= 0.05
+
+
 def test_lifecycle_disabled_never_dispatches(emb):
     idx = build(jax.random.PRNGKey(1), emb, kind="rq", k=K, iters=2)
     calls = []

@@ -160,3 +160,38 @@ def test_sampled_ce_backward_compiles(one_chip, width, table_dtype):
     _compile(bwd, *(s[n] for n in ("g", "h", "pos_emb", "neg_emb",
                                     "pos_scale", "neg_scale", "log_q",
                                     "neg_ids", "pos_ids", "g")))
+
+
+def test_kernels_keep_their_names_under_vmap_and_grad(one_chip):
+    """Each kernel's custom call is named after its `pallas_call(name=)`
+    whatever transformation wraps it, as the decode head's vmap and the
+    training head's grad do: the benchmark finds kernels in the device
+    trace by these names."""
+    import re
+
+    from repro.kernels.sampled_ce.ops import sampled_ce_op
+    s = _structs(one_chip, z=((4, 128), jnp.float32),
+                 cb=((16, 128), jnp.float32), counts=((16, 16), jnp.float32),
+                 h=((2, 256, 128), jnp.float32),
+                 pe=((2, 256, 128), jnp.float32),
+                 ne=((256, 128), jnp.float32), lq=((2, 256), jnp.float32),
+                 ni=((256,), jnp.int32), pi=((2, 256), jnp.int32))
+
+    def tables(z, cb, counts):
+        return jax.vmap(lambda r: midx_probs(
+            jnp.repeat(r[None], 256, 0), cb, cb, counts, split=False))(z)
+
+    def loss(h, pe, ne, lq, ni, pi):
+        return jnp.sum(jax.vmap(
+            lambda hb, pb, lb, ib: sampled_ce_op(hb, pb, ne, lb, ni, ib))(
+                h, pe, lq, pi))
+
+    texts = [jax.jit(tables).lower(s["z"], s["cb"], s["counts"])
+             .compile().as_text(),
+             jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                 *(s[n] for n in ("h", "pe", "ne", "lq", "ni", "pi")))
+             .compile().as_text()]
+    calls = {m.group(1) for text in texts for m in re.finditer(
+        r"^\s*%([a-z_]+)(?:\.\d+)? = .* custom-call\(", text, re.M)}
+    assert calls == {"midx_probs", "sampled_ce", "sampled_ce_bwd_dh",
+                     "sampled_ce_bwd_dne"}
