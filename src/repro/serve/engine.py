@@ -7,8 +7,9 @@ The engine owns:
   - a FIFO continuous-batching scheduler (`scheduler.Scheduler`);
   - one jitted slot-packed decode step over all `cfg.serve.max_slots` slots
     (inactive slots ride along masked, writing only the trash page);
-  - prefill, two ways: the legacy whole-prompt batched path (one
-    `models.decode.prefill` call per prompt-length group), or — with
+  - prefill, two ways: the legacy whole-prompt path (one fused
+    `models.decode.prefill` + `write_prefill` program call per request, at
+    one row, then one first-token call per prompt-length group), or — with
     `cfg.serve.prefill_chunk` — page-aligned chunks interleaved with decode
     waves so a long prompt never stalls in-flight decodes (DESIGN §13);
   - speculative decoding (`cfg.serve.spec_decode = k`, DESIGN §13): one
@@ -124,8 +125,8 @@ class EngineStats:
         if self.prefill_chunks:
             out["prefill_chunks"] = self.prefill_chunks
         if self.prefill_padded:
-            # the share of prefill rows that carry a prompt token: groups and
-            # chunks are padded to max_slots rows
+            # the share of prefill rows that carry a prompt token: a group
+            # runs one row per request, a chunk max_slots rows
             out["prefill_token_use"] = round(
                 self.prefill_tokens / self.prefill_padded, 4)
         out.update({k: round(v, 3) for k, v in metrics_mod.latency_summary(
@@ -259,37 +260,37 @@ class Engine:
 
         self._first_token = jax.jit(first_token_fn)
 
-        def prefill_fn(params, toks, **kw):
-            return prefill(cfg, params, toks, window=window, **kw)
+        def prefill_fn(params, state, toks, slots, **kw):
+            hidden, cache = prefill(cfg, params, toks, window=window, **kw)
+            state = write_prefill(cfg, state, cache, slots,
+                                  plen=toks.shape[1])
+            return hidden[:, -1], state
 
-        # compiles once per prompt-length bucket (groups are padded)
-        self._prefill = jax.jit(prefill_fn)
+        # one request's prefill and its cache write in one program, the
+        # state donated so the write lands in place; it always runs one row,
+        # so it compiles once per prompt length and returns only the last
+        # hidden state
+        self._prefill = jax.jit(prefill_fn, donate_argnums=(1,))
         # admission hot path, batched: one fused call builds every admitted
         # request's base key (fold_in(PRNGKey(seed), rid), bit-identical to
-        # the scalar construction), one vmapped fold_in derives a group's
-        # first-token keys, and write_prefill's eager scatter chain runs as
-        # a single jitted program — per-request host dispatches are what
-        # dominates admission cost on a CPU host, not the arithmetic
+        # the scalar construction) and one vmapped fold_in derives a group's
+        # first-token keys — per-request host dispatches are what dominates
+        # admission cost on a CPU host, not the arithmetic
         def bind_keys_fn(seeds, rids, slots, base_keys):
             base = jax.vmap(lambda s, r: jax.random.fold_in(
                 jax.random.PRNGKey(s), r))(seeds, rids)
             return base_keys.at[slots].set(base), base
 
         self._bind_keys_jit = jax.jit(bind_keys_fn, donate_argnums=(3,))
-
-        def write_prefill_fn(state, cache, slots, *, plen):
-            return write_prefill(cfg, state, cache, slots, plen=plen)
-
-        self._write_prefill = jax.jit(write_prefill_fn,
-                                      static_argnames=("plen",),
-                                      donate_argnums=(0,))
         spec_on = bool(self.spec_k)
 
-        def first_group_fn(params, index, base, gidx, hidden, hdraft,
+        # compiles once: `h_rows` is max_slots one-row hidden states, every
+        # other argument max_slots rows, plen1 traced
+        def first_group_fn(params, index, base, gidx, h_rows, hdraft,
                            slots, plen1):
             keys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
                 base[gidx], plen1)
-            h_last = hidden[:, -1]
+            h_last = jnp.concatenate(h_rows)
             if spec_on:   # the last prompt hidden seeds the first draft wave
                 hdraft = hdraft.at[slots].set(h_last.astype(hdraft.dtype))
             first = _sample_tokens(cfg, params, index, h_last, keys,
@@ -454,56 +455,42 @@ class Engine:
 
     # ------------------------------------------------------------ admission
     def _prefill_wave(self, admitted: list[SlotState]) -> None:
-        """Prefill newly admitted slots: one batched `prefill` call per
-        prompt-length group, cache written straight into the paged state.
-        First-token latency is charged per group, not per wave."""
+        """Prefill newly admitted slots: one fused prefill-and-write program
+        call per request, at one row, cache written straight into the paged
+        state; then one call per prompt-length group draws the group's first
+        tokens. First-token latency is charged per group, not per wave."""
         # pool.alloc already updated the host table; push it to device first
-        # so write_prefill sees the new page rows
+        # so the cache write sees the new page rows
         if "page_table" in self.state:
             self.state["page_table"] = jnp.asarray(self.pool.table)
         base = self._bind_keys(admitted)
+        b = self.cfg.serve.max_slots
         groups: dict[int, list[int]] = {}
         for i, ss in enumerate(admitted):
             groups.setdefault(len(ss.request.tokens), []).append(i)
         for plen, idxs in groups.items():
             sss = [admitted[i] for i in idxs]
-            # pad the group to max_slots rows so each prompt-length bucket
-            # compiles exactly once (batch composition never changes a row's
-            # arithmetic, so padding cannot change any request's output)
-            g, b = len(sss), self.cfg.serve.max_slots
+            g = len(sss)
             self.stats.prefill_tokens += g * plen
-            self.stats.prefill_padded += b * plen
-            with span("engine.prefill_group", plen=plen, tokens=g * plen,
-                      padded=b * plen):
+            self.stats.prefill_padded += g * plen
+            with span("engine.prefill_group", plen=plen, rows=g,
+                      tokens=g * plen, padded=g * plen):
                 t0 = time.perf_counter()
-
-                def stack(rows):
-                    rows = list(rows) + [rows[0]] * (b - g)
-                    return jnp.asarray(np.stack(rows))
-
-                toks = stack([ss.request.tokens for ss in sss])
-                kw = {}
-                if self.cfg.family == "vlm":
-                    kw["image_emb"] = stack([ss.request.image_emb
-                                             for ss in sss])
-                if self.cfg.family == "audio":
-                    kw["frames"] = stack([ss.request.frames for ss in sss])
-                hidden, cache = self._prefill(self.params, toks, **kw)
-                # pad the slot list the same way: the padded cache rows
-                # duplicate row 0 bitwise, so writing slot[0] again is a
-                # no-op — and every write_prefill call keeps a fixed shape
-                # (no per-group-size recompiles of its eager scatters)
-                slots = np.asarray([ss.slot for ss in sss] +
-                                   [sss[0].slot] * (b - g), np.int32)
-                self.state = self._write_prefill(self.state, cache, slots,
-                                                 plen=plen)
-                # key folding + spec hdraft stash + first-token sampling,
-                # fused: one dispatch instead of four (host dispatch is the
-                # admission bottleneck on a CPU host)
-                gidx = np.asarray(idxs + [idxs[0]] * (b - g), np.int32)
+                # each request prefills alone, at one row: its arithmetic
+                # cannot depend on what was admitted with it (a TPU may
+                # round a one-row matmul differently from a four-row one),
+                # and the program compiles once per prompt length
+                h_rows = [self._prefill_one(ss) for ss in sss]
+                # pad to max_slots rows duplicating row 0, so the first-token
+                # program compiles once and its padded hdraft writes repeat
+                # row 0's
+                pad = b - g
                 first, self._hdraft = self._first_group(
-                    self.params, self.index, base, jnp.asarray(gidx), hidden,
-                    self._hdraft, jnp.asarray(slots), plen - 1)
+                    self.params, self.index, base,
+                    jnp.asarray(idxs + idxs[:1] * pad, jnp.int32),
+                    tuple(h_rows + h_rows[:1] * pad), self._hdraft,
+                    jnp.asarray([ss.slot for ss in sss] + [sss[0].slot] * pad,
+                                jnp.int32), plen - 1)
                 with span("engine.device_wait"):
                     first = np.asarray(first)
                 for ss, tok in zip(sss, first[:g]):
@@ -514,6 +501,20 @@ class Engine:
                     ss.latencies.append(dt)
                 self.stats.latencies_s.extend(dt for _ in sss)
         self.stats.generated += len(admitted)
+
+    def _prefill_one(self, ss: SlotState) -> jax.Array:
+        """Prefill one request's prompt into its slot (one dispatch of the
+        fused prefill-and-write program); returns its last hidden state,
+        [1, d_model]."""
+        req, kw = ss.request, {}
+        if self.cfg.family == "vlm":
+            kw["image_emb"] = jnp.asarray(req.image_emb[None])
+        if self.cfg.family == "audio":
+            kw["frames"] = jnp.asarray(req.frames[None])
+        h_last, self.state = self._prefill(
+            self.params, self.state, jnp.asarray(req.tokens[None]),
+            jnp.asarray([ss.slot], jnp.int32), **kw)
+        return h_last
 
     def _admit_chunked(self, admitted: list[SlotState]) -> None:
         """Chunked-mode admission: bind keys and queue the slot for prefill
@@ -578,10 +579,12 @@ class Engine:
             self.stats.latencies_s.append(ss.prefill_s)
 
     def warmup(self, prompt_lens) -> None:
-        """Absorb jit compiles — one prefill per prompt-length bucket plus
-        the slot-packed decode step — then reset stats, so subsequent runs
-        report steady-state throughput/latency. Callers pass the same bucket
-        set their traffic draws prompt lengths from."""
+        """Absorb jit compiles — one prefill per prompt-length bucket, the
+        first-token program and the slot-packed decode step — then reset
+        stats, so subsequent runs report steady-state throughput/latency.
+        A prefill runs one row whatever its group, so one request per bucket
+        compiles every prefill program. Callers pass the same bucket set
+        their traffic draws prompt lengths from."""
         rng = np.random.default_rng(0)
         reqs = []
         for i, plen in enumerate(sorted(set(prompt_lens))):
@@ -796,10 +799,12 @@ class Engine:
         wave structure, so per-slot streams line up). The solo engine keeps
         this engine's slot count, the other slots idle: a TPU may round a
         one-row matmul differently from an eight-row one, so only the same
-        shapes make "alone" comparable bit for bit. It is cached across
-        calls so repeated verification doesn't recompile its prefill/decode
-        programs; reusing its state is safe because a recycled slot's reads
-        are masked to the new request's own writes."""
+        shapes make "alone" comparable bit for bit. A prompt prefills at one
+        row in both engines, whatever group it was admitted with. The solo
+        engine is cached across calls so repeated verification doesn't
+        recompile its prefill/decode programs; reusing its state is safe
+        because a recycled slot's reads are masked to the new request's own
+        writes."""
         if getattr(self, "_solo", None) is None:
             self._solo = Engine(self.cfg, self.params, index=self.index,
                                 head=self.head, window=self.window,

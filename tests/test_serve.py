@@ -190,35 +190,86 @@ def _tokens(cfg, key, s):
 # engine: interleaved == solo
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch,head", [("paper-lm", "midx"),
-                                       ("paper-lm", "full"),
-                                       ("mamba2-370m", "midx"),
-                                       ("qwen2-moe-a2.7b", "midx")])
-def test_engine_interleaved_matches_single(arch, head):
+_INTERLEAVED = [("paper-lm", "midx"), ("paper-lm", "full"),
+                ("mamba2-370m", "midx"), ("qwen2-moe-a2.7b", "midx")]
+
+
+@pytest.mark.parametrize("arch,head,group", [
+    *(pytest.param(a, h, False, id=f"{a}-{h}") for a, h in _INTERLEAVED),
+    *(pytest.param(a, h, True, id=f"{a}-{h}-group3")
+      for a, h in _INTERLEAVED)])
+def test_engine_interleaved_matches_single(arch, head, group):
     """Requests of different lengths interleaved through shared slots give
     outputs identical to running each request alone at the same seed.
 
     Includes MoE with a drop-inducing capacity factor: expert dispatch is
     vmapped per batch row, so capacity competition stays within a request
-    and batch composition still cannot change its tokens."""
+    and batch composition still cannot change its tokens.
+
+    `group`: three requests of one prompt length arrive together on four
+    slots, so they form one prefill group, and each must equal its replay
+    alone: a prompt prefills at one row whatever group it came in."""
     import dataclasses
     cfg = get_config(arch).reduced()
     if cfg.family == "moe":
         cfg = dataclasses.replace(cfg, capacity_factor=0.5)
-    cfg = cfg.with_serve(max_slots=2, page_size=4, max_seq=32)
+    cfg = cfg.with_serve(max_slots=4 if group else 2, page_size=4,
+                         max_seq=32)
     rng = np.random.default_rng(0)
+    sizes = ([(7, 5), (7, 4), (7, 6)] if group
+             else [(6, 5), (9, 7), (6, 3), (11, 6)])
     reqs = [Request(rid=i,
                     tokens=rng.integers(0, cfg.vocab_size,
                                         size=int(l)).astype(np.int32),
                     max_new=int(n), seed=3)
-            for i, (l, n) in enumerate([(6, 5), (9, 7), (6, 3), (11, 6)])]
+            for i, (l, n) in enumerate(sizes)]
     eng = Engine(cfg, init_key=jax.random.PRNGKey(1), head=head)
     res = eng.run(reqs)
-    assert eng.stats.waves >= 2              # continuous batching engaged
+    if group:
+        assert eng.stats.prefill_padded == 3 * 7     # one row per request
+    else:
+        assert eng.stats.waves >= 2          # continuous batching engaged
     for r in reqs:
         assert res[r.rid].tokens.shape == (r.max_new,)
         np.testing.assert_array_equal(res[r.rid].tokens,
                                       eng.replay_single(r))
+
+
+@pytest.mark.parametrize("arch,spec", [("paper-lm", 0), ("paper-lm", 2),
+                                       ("mamba2-370m", 0)])
+def test_warmup_leaves_nothing_to_compile(arch, spec):
+    """After `warmup(prompt_lens)`, runs at every group size from 1 to
+    max_slots, at every warmed prompt length, add no entry to the cache of
+    any of the engine's jitted programs: the prefill runs one row, so it has
+    one program per prompt length whatever the group (a solo replay runs the
+    very program its group ran), and the first-token program takes max_slots
+    rows."""
+    cfg = get_config(arch).reduced().with_serve(
+        max_slots=4, page_size=4, max_seq=32, spec_decode=spec)
+    eng = Engine(cfg, init_key=jax.random.PRNGKey(0), head="midx")
+    plens = [5, 9]
+    eng.warmup(plens)
+
+    def sizes():
+        return {n: f._cache_size() for n, f in vars(eng).items()
+                if hasattr(f, "_cache_size")}
+
+    warm = sizes()
+    assert warm["_prefill"] == len(plens)   # one row, whatever the group
+    assert warm["_first_group"] == 1     # max_slots rows, whatever the group
+    rng = np.random.default_rng(0)
+    rid = 0
+    for plen in plens:
+        for g in range(1, cfg.serve.max_slots + 1):
+            reqs = [Request(rid=rid + i, tokens=rng.integers(
+                        0, cfg.vocab_size, plen).astype(np.int32),
+                            max_new=3, seed=1) for i in range(g)]
+            rid += g
+            res = eng.run(reqs)
+            assert all(r.status == "ok" for r in res.values())
+    assert eng.stats.prefill_padded == eng.stats.prefill_tokens == sum(
+        plen * g for plen in plens for g in range(1, 5))
+    assert sizes() == warm
 
 
 def test_engine_page_pressure_queues_requests():

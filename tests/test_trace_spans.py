@@ -98,8 +98,10 @@ def test_engine_spans_count_the_work(tmp_path, mode):
         # each group's real rows, over the wave: every request admitted once
         assert sum(s.counts["tokens"] // s.counts["plen"] for s in groups) \
             == len(reqs)
-        assert all(s.counts["padded"] == 4 * s.counts["plen"]
-                   for s in groups)
+        # a group runs one prefill row per request: nothing is padding
+        for s in groups:
+            assert s.counts["rows"] == s.counts["tokens"] // s.counts["plen"]
+            assert s.counts["padded"] == s.counts["rows"] * s.counts["plen"]
     st, prefills = eng.stats, groups + chunks
     assert st.prefill_tokens == sum(s.counts["tokens"] for s in prefills)
     assert st.prefill_padded == sum(s.counts["padded"] for s in prefills)
@@ -121,6 +123,53 @@ def test_engine_spans_count_the_work(tmp_path, mode):
     for w in waits:
         assert any(t.holds(w) for t in ticks)
         assert any(s.holds(w) for s in work)
+
+
+def _rows_engine(max_slots: int) -> Engine:
+    cfg = get_config("paper-lm").reduced().with_serve(
+        max_slots=max_slots, page_size=4, max_seq=16)
+    return Engine(cfg, init_key=jax.random.PRNGKey(0), head="midx")
+
+
+def _same_length(eng, n, plen, rid0=0):
+    rng = np.random.default_rng(rid0)
+    return [Request(rid=rid0 + i, tokens=rng.integers(
+                0, eng.cfg.vocab_size, plen).astype(np.int32),
+                    max_new=2, seed=1) for i in range(n)]
+
+
+@pytest.mark.parametrize("g", [1, 3, 5, 16])
+def test_prefill_group_runs_one_row_per_request(tmp_path, g):
+    """g requests of one prompt length, admitted together on 16 slots, form
+    one prefill group of g one-row prefills: no row is padding."""
+    eng = _rows_engine(16)
+    reqs = _same_length(eng, g, 6)
+    res, spans = record(tmp_path, lambda: eng.run(reqs))
+    (group,) = named(spans, "engine.prefill_group")
+    assert group.counts == {"plen": 6, "rows": g, "tokens": g * 6,
+                            "padded": g * 6}
+    assert eng.stats.prefill_padded == eng.stats.prefill_tokens == g * 6
+    assert eng._prefill._cache_size() == 1     # one row, whatever g
+    assert sorted(res) == list(range(g))
+    assert all(r.status == "ok" for r in res.values())
+
+
+def test_prefill_groups_of_one_wave_sum_to_the_stats(tmp_path):
+    """Groups of 1, 3 and 5 requests of three prompt lengths in one
+    admission wave run 1, 3 and 5 rows; `EngineStats.prefill_padded` is
+    the sum of their spans' `padded`."""
+    eng = _rows_engine(16)
+    reqs = (_same_length(eng, 1, 4) + _same_length(eng, 3, 6, rid0=1)
+            + _same_length(eng, 5, 8, rid0=4))
+    res, spans = record(tmp_path, lambda: eng.run(reqs))
+    assert sorted(res) == list(range(9))
+    groups = sorted(named(spans, "engine.prefill_group"),
+                    key=lambda s: s.counts["plen"])
+    assert [(s.counts["plen"], s.counts["rows"], s.counts["padded"])
+            for s in groups] == [(4, 1, 4), (6, 3, 18), (8, 5, 40)]
+    assert eng.stats.prefill_padded == sum(s.counts["padded"]
+                                           for s in groups) == 62
+    assert eng.stats.prefill_tokens == 4 + 18 + 40
 
 
 def _toy_refresh(params, index, key):
