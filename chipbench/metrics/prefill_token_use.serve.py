@@ -1,7 +1,8 @@
 """Per cent of the prefill rows that carry a prompt token: the `tokens`
-over the `padded` counts (each prefill group or chunk runs `max_slots` rows)
-of the engine's `engine.prefill_group` and `engine.prefill_chunk` spans that
-start in the traced window."""
+over the `padded` counts of the engine's `engine.prefill_group` and
+`engine.prefill_chunk` spans that start in the traced window. A group runs
+one row per request (`padded` = requests x prompt length); a chunk runs
+`max_slots` rows of `chunk` tokens (`padded` = max_slots x chunk)."""
 from chipbench import program_trace
 
 

@@ -159,3 +159,20 @@ def test_files_added_in_a_new_directory_are_found_and_run(tmp_path):
     assert out["metrics"]["train_steps_per_s"]["value"] > 0
     # train_tokens_per_s lists the cells it is read in; this is not one
     assert set(out["metrics"]) == {"setup_s", "train_steps_per_s"}
+
+
+def test_bounds_and_the_metric_each_layer_moves():
+    """Every end-to-end bound lies in [0.01, 0.25]; every per-layer metric
+    moves an end-to-end metric that exists and is reported in each cell the
+    per-layer metric is read in."""
+    b = bench.load_json(REPO / "BENCHMARK.json")
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    cells = {w["name"] for w in b["workloads"]}
+    for m in e2e.values():
+        assert 0.01 <= m["bound"] <= 0.25, m["name"]
+    for m in b["per_layer"]:
+        assert m["moves"] in e2e, m["name"]
+        for cell in m.get("workloads", cells):
+            assert m["moves"] in [x["name"] for x in
+                                  bench.metrics_of(b, cell, "end_to_end")], (
+                m["name"], cell)
